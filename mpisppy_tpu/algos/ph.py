@@ -35,6 +35,7 @@ import numpy as np
 from mpisppy_tpu import global_toc
 from mpisppy_tpu.core.batch import ScenarioBatch, concretize
 from mpisppy_tpu.ops import pdhg
+from mpisppy_tpu.parallel.mesh import shard_like_batch
 from mpisppy_tpu.telemetry import profiler as _prof
 
 Array = jax.Array
@@ -149,7 +150,7 @@ def kernel_opts(opts: PHOptions) -> PHOptions:
     limits) to fixed values before an options object becomes a jit
     static argument: they do not affect the compiled program, and
     letting them into the hash caused spurious recompiles (a multi-
-    minute remote compile per distinct max_iterations value)."""
+    minute compile per distinct max_iterations value)."""
     return dataclasses.replace(
         opts, default_rho=0.0, max_iterations=0, conv_thresh=0.0,
         display_progress=False, time_limit=None)
@@ -268,7 +269,9 @@ class PH:
 
     # -- algorithm step hooks (overridden by APH) -------------------------
     def _iter0_impl(self):
-        return ph_iter0(self.batch, self.rho, kernel_opts(self.options))
+        st, tb, cert = ph_iter0(self.batch, self.rho,
+                                kernel_opts(self.options))
+        return shard_like_batch(st, self.batch), tb, cert
 
     def _iterk_impl(self):
         return ph_iterk(self.batch, self.state, kernel_opts(self.options))

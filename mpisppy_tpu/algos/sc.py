@@ -402,10 +402,7 @@ class SchurComplement:
             else contextlib.nullcontext()
         dt = jnp.float64
         t0 = time.perf_counter()
-        # jax.enable_x64 left the top-level namespace in current JAX;
-        # the context manager lives in jax.experimental now
-        from jax.experimental import enable_x64 as _enable_x64
-        with _enable_x64(), ctx:
+        with jax.enable_x64(), ctx:
             w, x, done, mu, resid = _sc_solve(
                 jnp.asarray(s["G"], dt), jnp.asarray(s["b"], dt),
                 jnp.asarray(s["lw"], dt), jnp.asarray(s["uw"], dt),

@@ -18,7 +18,7 @@
 #     outstanding dispatches gates every window: when the device
 #     pipeline is full, dispatching threads queue on the semaphore and
 #     their windows KEEP ACCUMULATING requests while they wait — load
-#     turns into batch occupancy instead of tunnel depth, which is the
+#     turns into batch occupancy instead of queue depth, which is the
 #     whole point.  max_inflight=2 is the classic double buffer: one
 #     dispatch executing, one staged.
 #   * SHAPE DISCIPLINE (buckets + compile watch).  Megabatches pad up
@@ -780,7 +780,7 @@ class SolveScheduler:
     def _run_window(self, win: _Window):
         # backpressure FIRST: while this thread queues on the in-flight
         # semaphore the window is still open, so a storm accumulates
-        # into occupancy rather than tunnel depth
+        # into occupancy rather than device queue depth
         self._sem.acquire()
         try:
             with self._lock:

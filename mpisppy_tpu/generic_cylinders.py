@@ -431,12 +431,15 @@ def _report_device_profile(profile_dir: str) -> None:
     and `telemetry gate` consume."""
     import os
 
+    import jax
+
     from mpisppy_tpu.telemetry import deviceprof, roofline
     try:
         cap = deviceprof.newest_capture(profile_dir)
         if cap is None:
             return
-        rep = roofline.roofline(deviceprof.build_timeline(cap))
+        rep = roofline.roofline(deviceprof.build_timeline(cap),
+                                jax.devices()[0].device_kind)
     except (OSError, ValueError) as e:
         global_toc(f"device profile unreadable under {profile_dir}: {e}",
                    True)
@@ -547,4 +550,6 @@ def main(args=None):
 
 
 if __name__ == "__main__":
+    from mpisppy_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

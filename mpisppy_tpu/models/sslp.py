@@ -277,10 +277,12 @@ def scenario_program(num_scens: int, seed: int = 0, start: int = 0,
                      inst_seed: int = 0, lp_relax: bool = False,
                      instance: dict | None = None):
     """ScenarioProgram drawing ClientPresent through scengen keys."""
+    import jax
     import jax.numpy as jnp
-    from jax import random as jrandom
 
-    from mpisppy_tpu.scengen.program import ScenarioProgram, scen_key
+    from mpisppy_tpu.scengen.program import (
+        ScenarioProgram, scen_key, uniform_at,
+    )
 
     inst = instance if instance is not None \
         else synthetic_instance(n_servers, n_clients, inst_seed)
@@ -299,10 +301,16 @@ def scenario_program(num_scens: int, seed: int = 0, start: int = 0,
     bu0_f = jnp.asarray(bu0, jnp.float32)
 
     def sampler(base_key, idx):
-        h = (jrandom.uniform(scen_key(base_key, idx), (m,),
-                             jnp.float32) < 0.5).astype(jnp.float32)
-        return {"bl": bl0_f.at[n:n + m].set(h),
-                "bu": bu0_f.at[n:n + m].set(h)}
+        # row r = n + i draws ClientPresent_i from counter i; elementwise
+        # over the full row vector (no scatter), so the sampler also
+        # lowers inside the Pallas tile synth
+        rows = jax.lax.iota(jnp.int32, nrows)
+        client = (rows >= n) & (rows < n + m)
+        u = uniform_at(scen_key(base_key, idx),
+                       (rows - n).astype(jnp.uint32))
+        h = (u < 0.5).astype(jnp.float32)
+        return {"bl": jnp.where(client, h, bl0_f),
+                "bu": jnp.where(client, h, bu0_f)}
 
     integer_eff = np.zeros_like(integer) if lp_relax else integer
     return ScenarioProgram(

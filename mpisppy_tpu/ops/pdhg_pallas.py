@@ -113,8 +113,37 @@ def _dot3(v_split, M_hi, M_lo):
     return acc
 
 
+#: lane width of the packed per-scenario scalar operand (see
+#: _pack_scalars)
+_SCAL_W = 128
+
+
+def _pack_scalars(tau, sigma, done, S_p: int, dt):
+    """tau/sigma/done (S,) -> one lane-dense (S_p, 128) operand with the
+    three scalars in lanes 0/1/2.  Mosaic slices HBM operands in whole
+    (8, 128) tiles, so three (S_p, 1) columns cannot ride the manual DMA
+    pipeline (v5e: "Slice shape along dimension 1 must be aligned to
+    tiling (128)"); pad scenarios get tau = sigma = 1, done = 1
+    (frozen)."""
+    cols = jnp.stack([jnp.asarray(v, dt) for v in (tau, sigma, done)],
+                     axis=-1)
+    pad = S_p - cols.shape[0]
+    if pad:
+        cols = jnp.concatenate([cols, jnp.ones((pad, 3), dt)])
+    return _pad_last(cols, _SCAL_W, 0.0)
+
+
+def _unpack_scalars(scal):
+    """In-kernel inverse of _pack_scalars: three (T, 1) columns, each a
+    masked lane sum (exact: every other lane adds 0.0) — a lane
+    reduction lowers everywhere, an unaligned lane slice does not."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, scal.shape, 1)
+    return tuple(jnp.sum(jnp.where(lane == k, scal, 0.0), axis=1,
+                         keepdims=True) for k in range(3))
+
+
 def _tile_math(n_iters: int, precision, has_cones: bool,
-               tau0, sigma0, done, c, q, l, u, bl, bu,  # noqa: E741
+               scal, c, q, l, u, bl, bu,  # noqa: E741
                mats, cone_vals, x0, y0, xs0, ys0):
     """All n_iters PDHG iterations for one scenario tile, on VALUES.
 
@@ -138,6 +167,7 @@ def _tile_math(n_iters: int, precision, has_cones: bool,
     (T, m) x (m, C) dot IS a segment sum with static shapes.
     """
     three_pass = precision == jax.lax.Precision.HIGH
+    tau0, sigma0, done = _unpack_scalars(scal)
     live = 1.0 - done         # (T, 1) 1.0 = still running
     # Done-masking folds into the step sizes: with tau = sigma = 0 the
     # iteration is an exact no-op (x1 = clip(x, l, u) = x since every
@@ -233,18 +263,16 @@ def _window_kernel(n_iters: int, precision, has_cones: bool, *refs):
     # a single-dot mode, 4 for the bf16x3 split) — dead operands would
     # cost a DMA + VMEM residency per grid step
     nmat = 4 if three_pass else 2
-    (tau_ref, sigma_ref, done_ref,
-     c_ref, q_ref, l_ref, u_ref, bl_ref, bu_ref) = refs[:9]
-    mat_refs = refs[9:9 + nmat]
-    k = 9 + nmat
+    (scal_ref, c_ref, q_ref, l_ref, u_ref, bl_ref, bu_ref) = refs[:7]
+    mat_refs = refs[7:7 + nmat]
+    k = 7 + nmat
     cone_refs = refs[k:k + 7] if has_cones else ()
     k += 7 if has_cones else 0
     (x0_ref, y0_ref, xs0_ref, ys0_ref,
      x_ref, y_ref, xs_ref, ys_ref) = refs[k:]
 
     x, y, xs, ys = _tile_math(
-        n_iters, precision, has_cones,
-        tau_ref[:], sigma_ref[:], done_ref[:],
+        n_iters, precision, has_cones, scal_ref[:],
         c_ref[:], q_ref[:], l_ref[:], u_ref[:], bl_ref[:], bu_ref[:],
         tuple(r[:] for r in mat_refs),
         tuple(r[:] for r in cone_refs),
@@ -410,16 +438,7 @@ def run_window(p, x: Array, y: Array, x_sum: Array, y_sum: Array,
     xs_p = prep(x_sum, n_p, 0.0)
     ys_p = prep(y_sum, m_p, 0.0)
 
-    def prep_s(v, fill):
-        v = jnp.asarray(v, dt)
-        pad = S_p - v.shape[0]
-        if pad:
-            v = jnp.concatenate([v, jnp.full((pad,), fill, dt)])
-        return v[:, None]
-
-    tau_p = prep_s(tau, 1.0)
-    sigma_p = prep_s(sigma, 1.0)
-    done_p = prep_s(done.astype(dt), 1.0)  # pad scenarios frozen
+    scal = _pack_scalars(tau, sigma, done, S_p, dt)
 
     has_cones = p.cones is not None
     cone_ops = ()
@@ -447,7 +466,7 @@ def run_window(p, x: Array, y: Array, x_sum: Array, y_sum: Array,
     if pipeline:
         xo, yo, xso, yso = _run_window_pipelined(
             n_iters, prec, has_cones, tile_s, S_p, n_p, m_p, dt,
-            mats, (tau_p, sigma_p, done_p, c, q, l, u, bl, bu),
+            mats, (scal, c, q, l, u, bl, bu),
             cone_ops, (x_p, y_p, xs_p, ys_p), interpret, synth)
         return (xo[:S, :n], yo[:S, :m], xso[:S, :n], yso[:S, :m])
 
@@ -460,7 +479,7 @@ def run_window(p, x: Array, y: Array, x_sum: Array, y_sum: Array,
         return pl.BlockSpec((tile_s, width), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
 
-    sspec = pl.BlockSpec((tile_s, 1), lambda i: (i, 0),
+    sspec = pl.BlockSpec((tile_s, _SCAL_W), lambda i: (i, 0),
                          memory_space=pltpu.VMEM)
     aspec = pl.BlockSpec((m_p, n_p), lambda i: (0, 0),
                          memory_space=pltpu.VMEM)
@@ -491,7 +510,7 @@ def run_window(p, x: Array, y: Array, x_sum: Array, y_sum: Array,
     xo, yo, xso, yso = pl.pallas_call(
         partial(_window_kernel, n_iters, prec, has_cones),
         grid=grid,
-        in_specs=[sspec, sspec, sspec,
+        in_specs=[sspec,
                   vspec(c, n_p), vspec(q, n_p), vspec(l, n_p), vspec(u, n_p),
                   vspec(bl, m_p), vspec(bu, m_p),
                   *mat_specs, *cone_specs,
@@ -500,7 +519,7 @@ def run_window(p, x: Array, y: Array, x_sum: Array, y_sum: Array,
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
-    )(tau_p, sigma_p, done_p, c, q, l, u, bl, bu, *mats, *cone_ops,
+    )(scal, c, q, l, u, bl, bu, *mats, *cone_ops,
       x_p, y_p, xs_p, ys_p)
 
     return (xo[:S, :n], yo[:S, :m], xso[:S, :n], yso[:S, :m])
@@ -530,13 +549,13 @@ def _run_window_pipelined(n_iters, prec, has_cones, tile_s, S_p, n_p, m_p,
     that slot; the final two write-backs drain after the tile loop.
 
     Layout bookkeeping is static python: `dma_names` fixes the operand
-    order (always-batched tau/sigma/done + solver state, plus whichever
-    of c/q/l/u/bl/bu[/shift] are per-scenario); shared operands bypass
-    the pipeline entirely.
+    order (the always-batched packed tau/sigma/done operand and the
+    solver state, plus whichever of c/q/l/u/bl/bu[/shift] are
+    per-scenario); shared operands bypass the pipeline entirely.
     """
     n_tiles = S_p // tile_s
-    tau_p, sigma_p, done_p, c, q, l, u, bl, bu = params  # noqa: E741
-    named = [("tau", tau_p, 1), ("sigma", sigma_p, 1), ("done", done_p, 1),
+    scal, c, q, l, u, bl, bu = params  # noqa: E741
+    named = [("scal", scal, _SCAL_W),
              ("c", c, n_p), ("q", q, n_p), ("l", l, n_p), ("u", u, n_p),
              ("bl", bl, m_p), ("bu", bu, m_p)]
     cone_shared = ()
@@ -631,8 +650,7 @@ def _run_window_pipelined(n_iters, prec, has_cones, tile_s, S_p, n_p, m_p,
             if has_cones:
                 cone_vals = (v["shift"],) + cone_shared_vals
             x1, y1, xs1, ys1 = _tile_math(
-                n_iters, prec, has_cones,
-                v["tau"], v["sigma"], v["done"],
+                n_iters, prec, has_cones, v["scal"],
                 v["c"], v["q"], v["l"], v["u"], v["bl"], v["bu"],
                 mat_vals, cone_vals, v["x"], v["y"], v["xs"], v["ys"])
 
@@ -657,7 +675,7 @@ def _run_window_pipelined(n_iters, prec, has_cones, tile_s, S_p, n_p, m_p,
             out_dma(j, (n_tiles - 1) % 2, n_tiles - 1).wait()
 
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     n_resident = len(mats) + len(shared_arrs) + len(cone_shared) \
         + len(synth_consts)
     return pl.pallas_call(

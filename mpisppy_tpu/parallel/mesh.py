@@ -46,19 +46,8 @@ def init_multihost(coordinator_address: str,
 
     if cpu_devices_per_process is not None:
         _jax.config.update("jax_platforms", "cpu")
-        try:
-            _jax.config.update("jax_num_cpu_devices",
-                               int(cpu_devices_per_process))
-        except AttributeError:
-            # older JAX spells the knob as an XLA flag, read when the
-            # backend initializes (distributed.initialize below
-            # triggers that, so setting the env var here still works)
-            import os
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    flags + " --xla_force_host_platform_device_count="
-                    f"{int(cpu_devices_per_process)}").strip()
+        _jax.config.update("jax_num_cpu_devices",
+                           int(cpu_devices_per_process))
         _jax.config.update("jax_cpu_collectives_implementation", "gloo")
     _jax.distributed.initialize(coordinator_address=coordinator_address,
                                 num_processes=num_processes,
@@ -184,3 +173,24 @@ def shard_batch(batch, mesh: Mesh, pad: bool = False):
         var_prob=None if batch.var_prob is None
         else jax.device_put(batch.var_prob, shard),
     )
+
+
+def shard_like_batch(tree, batch):
+    """Re-place the scenario-major leaves (leading axis == S) of `tree`
+    that came out of a jitted step fully replicated onto the batch's
+    scenario sharding.  SPMD propagation leaves a state field that only
+    ever holds a broadcast constant (PDHG guard_resets, PH's z/xsqbar
+    with smoothing off, an untouched plane's omega) on every device in
+    full; once placed, the jitted steps keep it split S/n per device.
+    A no-op for a batch on one device."""
+    sh = getattr(batch.p, "sharding", None)
+    if not isinstance(sh, NamedSharding) or sh.mesh.size < 2:
+        return tree
+    S = batch.num_scenarios
+
+    def place(a):
+        if isinstance(a, jax.Array) and a.ndim >= 1 and a.shape[0] == S \
+                and a.sharding.is_fully_replicated:
+            return jax.device_put(a, sh)
+        return a
+    return jax.tree_util.tree_map(place, tree)

@@ -49,6 +49,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.random import threefry2x32_p
 
 from mpisppy_tpu.core.tree import ScenarioTree, two_stage_tree
 
@@ -61,8 +62,33 @@ FIELDS = ("c", "q", "A", "bl", "bu", "l", "u")
 def scen_key(base_key: Array, idx) -> Array:
     """The ONE key derivation of the subsystem: scenario `idx`'s
     counter-based key.  fold_in is threefry-backed and stateless, so
-    this is invariant to tiling/sharding/replication order."""
-    return jax.random.fold_in(base_key, idx)
+    this is invariant to tiling/sharding/replication order.
+
+    Bit for bit jax.random.fold_in(base_key, idx) on a raw uint32[2]
+    key, spelled through the threefry2x32 primitive: vmapped, it stays
+    elementwise and lowers inside a Mosaic kernel (the Pallas tile
+    synth), where the batched random_fold_in primitive does not."""
+    d = jnp.asarray(idx).astype(jnp.uint32)
+    k1, k2 = _threefry2x32(base_key[0], base_key[1], d * np.uint32(0), d)
+    return jnp.stack([k1, k2])
+
+
+def uniform_at(key: Array, counter: Array) -> Array:
+    """jax.random.uniform(key, shape, float32).ravel()[counter], bit for
+    bit (partitionable threefry, the installed default), for a uint32
+    counter array of any shape — from the threefry2x32 primitive alone,
+    for the same reason as scen_key."""
+    b1, b2 = _threefry2x32(key[0], key[1], counter * np.uint32(0), counter)
+    bits = ((b1 ^ b2) >> np.uint32(9)) | np.uint32(0x3F800000)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32) - 1.0
+
+
+def _threefry2x32(k1, k2, x1, x2):
+    """The primitive with its four operands broadcast to one shape
+    first: its lowering needs equal shapes, which vmap over a mix of
+    batched and unbatched operands does not give it."""
+    z = (k1 ^ k2 ^ x1 ^ x2) * np.uint32(0)   # keeps vmap's batch axis
+    return threefry2x32_p.bind(k1 + z, k2 + z, x1 + z, x2 + z)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)

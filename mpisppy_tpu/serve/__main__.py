@@ -69,6 +69,8 @@ def main(argv=None) -> int:
                   file=sys.stderr)
             return 1
     from mpisppy_tpu.serve.server import ServeOptions, WheelServer
+    from mpisppy_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     opts = ServeOptions(
         unix_path=args.unix, host=args.host,
         port=args.port if not args.unix else 0,

@@ -5,7 +5,7 @@
 # flight-recorder dump) and answers the first questions of every run of
 # a hub-and-spoke wheel (Knueven et al., MPC 2023): where did the wall
 # time go, which spoke produced the binding bounds, is the gap moving
-# or stalled, and is the dispatch tunnel healthy?
+# or stalled, and is the dispatch path healthy?
 #
 #   rows  = load_trace("trace.jsonl")
 #   model = build_run_model(rows)           # typed run -> iters -> events

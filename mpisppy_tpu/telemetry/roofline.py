@@ -55,10 +55,23 @@ DEVPROF_SCHEMA = "mpisppy-tpu-deviceprof/1"
 STREAM_CATEGORIES = frozenset({"data formatting",
                                "non-fusion elementwise", "broadcast"})
 
-#: v5e single-chip public-spec fallbacks when the capture carries no
-#: plane stats (json-only fixtures)
-V5E_PEAK_HBM_GBPS = 819.0
-V5E_PEAK_BF16_TFLOPS = 197.0
+#: Published single-chip peaks keyed by jax's `device_kind`: bf16
+#: TFLOP/s and HBM GB/s.  A capture's own plane stats win; this table
+#: serves captures that carry none (json-only traces).  A device that is
+#: not here is an error, never a default.
+PEAKS_SOURCE = 'Google Cloud documentation, "TPU v5e"'
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_tflops": 197.0, "hbm_gbps": 819.0},
+}
+
+
+def device_peaks(device_kind: str | None) -> dict:
+    """The published peaks of `device_kind` (see DEVICE_PEAKS)."""
+    if device_kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}: add it "
+            f"to telemetry/roofline.py DEVICE_PEAKS with its source")
+    return DEVICE_PEAKS[device_kind]
 
 
 def _union(intervals):
@@ -94,8 +107,10 @@ def _median(xs):
     return xs[len(xs) // 2] if xs else None
 
 
-def roofline(tl: dp.DeviceTimeline) -> dict:
-    """DeviceTimeline -> the machine report (JSON-able dict)."""
+def roofline(tl: dp.DeviceTimeline, device_kind: str | None = None) -> dict:
+    """DeviceTimeline -> the machine report (JSON-able dict).
+    `device_kind` (jax's) keys the peak table for a capture that
+    carries no peaks of its own."""
     hbm_exact = tl.has_memory_spaces
 
     def op_bytes(op):
@@ -132,8 +147,11 @@ def roofline(tl: dp.DeviceTimeline) -> dict:
     # -- whole-step achieved HBM rate ------------------------------------
     total_gb = sum(c["hbm_gb"] for c in cats.values())
     achieved = total_gb / module_s if module_s > 0 else None
-    peak_hbm = tl.peak_hbm_gbps or V5E_PEAK_HBM_GBPS
-    peak_tf = tl.peak_tflops or V5E_PEAK_BF16_TFLOPS
+    peak_hbm, peak_tf = tl.peak_hbm_gbps, tl.peak_tflops
+    if not (peak_hbm and peak_tf):
+        pk = device_peaks(device_kind)
+        peak_hbm = peak_hbm or pk["hbm_gbps"]
+        peak_tf = peak_tf or pk["bf16_tflops"]
 
     # -- stream (pure-data-movement) bandwidth ---------------------------
     # with exact memory spaces the sample keeps only HBM-DOMINATED
@@ -255,9 +273,9 @@ def _round(v, nd):
     return None if v is None else round(v, nd)
 
 
-def roofline_path(profile_dir: str) -> dict:
+def roofline_path(profile_dir: str, device_kind: str | None = None) -> dict:
     """Newest capture under `profile_dir` -> roofline report."""
-    return roofline(dp.build_timeline(profile_dir))
+    return roofline(dp.build_timeline(profile_dir), device_kind)
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,8 @@ GOLDEN_FARMER = os.path.join(HERE, "fixtures",
 PROFILE_S100K = os.path.join(REPO, "profile_trace_S100000")
 PROFILE_S10K = os.path.join(REPO, "profile_trace_S10000")
 CLI = [sys.executable, "-m", "mpisppy_tpu.telemetry"]
+# the golden json-only capture came from a v5e chip and carries no peaks
+V5E = "TPU v5 lite"
 ENV = {"PATH": "/usr/bin:/bin:/usr/local/bin", "JAX_PLATFORMS": "cpu",
        "HOME": os.path.expanduser("~")}
 
@@ -103,13 +105,23 @@ def test_roofline_s10k_sane():
 
 
 def test_roofline_golden_fixture_json_only_fallback():
-    rep = roofline.roofline(dp.build_timeline(GOLDEN_DEVICE))
+    rep = roofline.roofline(dp.build_timeline(GOLDEN_DEVICE), V5E)
     assert rep["byte_source"] == "bytes-accessed-all-spaces"
     assert rep["measured_stream_gbps"] > 0
     assert 0.0 <= rep["overlap_frac"] <= 1.0
     assert rep["dma"]["spans"] > 0
     # the fallback must announce its VMEM-reuse caveat
     assert any("bytes_accessed" in n for n in rep["notes"])
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    rep = roofline.roofline(dp.build_timeline(GOLDEN_DEVICE), V5E)
+    assert rep["peak_hbm_gbps"] == 819.0 and rep["peak_tflops"] == 197.0
+    # a capture without peaks on a device the table lacks is an error,
+    # not the v5e numbers
+    for kind in (None, "cpu", "TPU v4"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline.roofline(dp.build_timeline(GOLDEN_DEVICE), kind)
 
 
 def test_xplane_walker_rejects_garbage(tmp_path):
@@ -131,7 +143,7 @@ def test_xplane_walker_rejects_garbage(tmp_path):
 # CI gate: device-metric regressions must exit 2 (ISSUE 7 satellite)
 # ---------------------------------------------------------------------------
 def _golden_report(tmp_path):
-    rep = roofline.roofline(dp.build_timeline(GOLDEN_DEVICE))
+    rep = roofline.roofline(dp.build_timeline(GOLDEN_DEVICE), V5E)
     p = tmp_path / "device_golden.json"
     p.write_text(json.dumps(rep))
     return rep, p
@@ -159,7 +171,7 @@ def test_gate_passes_identical_device_report(tmp_path):
 
 def test_device_metrics_direction_aware():
     mets = regress.extract_metrics(
-        roofline.roofline(dp.build_timeline(GOLDEN_DEVICE)))
+        roofline.roofline(dp.build_timeline(GOLDEN_DEVICE), V5E))
     assert "device.measured_stream_gbps" in mets
     assert "device.overlap_frac" in mets
     # bandwidth falling regresses, rising does not

@@ -194,7 +194,7 @@ def test_dtype_census_catches_f64_promotion():
             (jnp.zeros(16, jnp.float32),)
 
     spec = ir_manifest.KernelSpec("fixture_f64", build)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         facts = _fixture_audit(spec)
     found = ir_passes.dtype_census_findings(spec, facts)
     assert len(found) == 1 and "float64" in found[0].message

@@ -497,10 +497,11 @@ def _b_ckpt_gather(fx):
 # ---------------------------------------------------------------------------
 # the manifest
 # ---------------------------------------------------------------------------
+# Pinned to the installed XLA (0.9.0): every sharded kernel's
+# scenario-axis reduction lowers to an all-reduce over both devices of
+# the 2-device audit mesh (replica_groups [1,2]<=[2]).
 _AR = frozenset({"all-reduce"})
-_AR_CP = frozenset({"all-reduce", "collective-permute"})
 _AG_AR = frozenset({"all-gather", "all-reduce"})
-_AG_AR_CP = frozenset({"all-gather", "all-reduce", "collective-permute"})
 
 #: scengen transients budget: the audit programs realize a ~few-KB
 #: farmer batch in-trace; a compiled high-water above this means an
@@ -513,7 +514,7 @@ _VIRTUAL_TEMP_BUDGET = 1 << 20      # 1 MiB
 MANIFEST: tuple[KernelSpec, ...] = (
     KernelSpec("ph_iter0", _b_ph_iter0,
                "PH iter0: plain solves + W seed + trivial bound",
-               collectives=_AR_CP, sharded=True, fast=True),
+               collectives=_AR, sharded=True, fast=True),
     KernelSpec("ph_iterk", _b_ph_iterk,
                "one PH iteration (the hub hot step)",
                collectives=_AR, sharded=True, fast=True),
@@ -522,7 +523,7 @@ MANIFEST: tuple[KernelSpec, ...] = (
                collectives=_AR, sharded=True, fast=True),
     KernelSpec("fused_iter0", _b_fused_iter0,
                "fused wheel iter0 (hub + 4 bound planes)",
-               collectives=_AR_CP, sharded=True),
+               collectives=_AR, sharded=True),
     KernelSpec("fused_iterk", _b_fused_iterk,
                "fused wheel iteration (monolithic plane program)",
                collectives=_AG_AR, sharded=True),
@@ -543,25 +544,25 @@ MANIFEST: tuple[KernelSpec, ...] = (
                collectives=_AR, sharded=True, fast=True),
     KernelSpec("xhat_evaluate", _b_xhat_evaluate,
                "xhat evaluate core (fixed-nonant recourse)",
-               collectives=_AR_CP, sharded=True, fast=True),
+               collectives=_AR, sharded=True, fast=True),
     KernelSpec("xhat_evaluate_warm", _b_xhat_evaluate_warm,
                "warm-state xhat evaluate core",
                collectives=_AR, sharded=True),
     KernelSpec("xhat_shuffle", _b_xhat_shuffle,
                "k-candidate shuffle evaluation",
-               collectives=_AR_CP, sharded=True),
+               collectives=_AR, sharded=True),
     KernelSpec("fwph_init", _b_fwph_init,
                "FWPH init (iter0 solves + column seed)",
-               collectives=_AR_CP, sharded=True),
+               collectives=_AR, sharded=True),
     KernelSpec("fwph_iter", _b_fwph_iter,
                "FWPH SDM iteration",
-               collectives=_AG_AR_CP, sharded=True),
+               collectives=_AG_AR, sharded=True),
     KernelSpec("lshaped_cuts", _b_lshaped_cuts,
                "L-shaped per-scenario cut extraction",
-               collectives=_AR_CP, sharded=True, fast=True),
+               collectives=_AR, sharded=True, fast=True),
     KernelSpec("cross_scen_cuts", _b_cross_scen_cuts,
                "cross-scenario cut launch (winner argmax)",
-               collectives=_AG_AR_CP, sharded=True),
+               collectives=_AG_AR, sharded=True),
     KernelSpec("bnb_round", _b_bnb_round,
                "batched-MIP best-first B&B round",
                collectives=_AR, sharded=True, fast=True),
@@ -580,7 +581,7 @@ MANIFEST: tuple[KernelSpec, ...] = (
                fast=True),
     KernelSpec("ph_iter0_virtual", _b_ph_iter0_virtual,
                "PH iter0 fed by a VirtualBatch (concretize path)",
-               collectives=_AR_CP, sharded=True, virtual=True,
+               collectives=_AR, sharded=True, virtual=True,
                temp_budget_bytes=_VIRTUAL_TEMP_BUDGET, fast=True),
     KernelSpec("ph_iterk_virtual", _b_ph_iterk_virtual,
                "PH iteration fed by a VirtualBatch (concretize path)",
