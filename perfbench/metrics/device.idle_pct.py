@@ -1,0 +1,9 @@
+"""device.idle_pct: share of the traced window in which no op ran on the
+device."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
