@@ -38,6 +38,7 @@ from mpisppy_tpu.algos import xhat as xhat_mod
 from mpisppy_tpu.core.batch import ScenarioBatch, concretize
 from mpisppy_tpu.ops import boxqp, pdhg
 from mpisppy_tpu.parallel.mesh import shard_like_batch
+from mpisppy_tpu.telemetry import profiler as _prof
 
 Array = jax.Array
 
@@ -694,19 +695,21 @@ class FusedPH(ph_mod.PH):
         else:
             scalars, xc, sc_, fc = inflight
         self._scalars_inflight = inflight
-        # the ONE place the hub loop blocks on the mesh: with an
-        # elastic MeshRuntime armed (parallel/elastic.py) the fetch is
-        # deadline-bounded and chaos-seamed — a straggler or lost host
-        # trips a typed MeshDegraded here instead of hanging the hub;
-        # without one, the plain fetch below is the whole cost
+        # the ONE place the hub loop blocks on the device, timed as the
+        # `scalar_wait` span (the enclosing subproblem_solve / iter0_solve
+        # span then times dispatch alone).  With an elastic MeshRuntime
+        # armed (parallel/elastic.py) the fetch is deadline-bounded and
+        # chaos-seamed — a straggler or lost host trips a typed
+        # MeshDegraded here instead of hanging the hub
         spcomm = getattr(self, "spcomm", None)
         rt = None if spcomm is None \
             else spcomm.options.get("mesh_runtime")
-        if rt is not None:
-            vals = rt.harvest(lambda: np.asarray(scalars),
-                              hub_iter=self._iter)
-        else:
-            vals = np.asarray(scalars)
+        with _prof.span(spcomm, "scalar_wait"):
+            if rt is not None:
+                vals = rt.harvest(lambda: np.asarray(scalars),
+                                  hub_iter=self._iter)
+            else:
+                vals = np.asarray(scalars)
         self.scalar_cache = dict(zip(SCALAR_KEYS, (float(v) for v in vals)))
         # device refs, transferred only when a spoke actually offers
         self.cand_cache = {"xhat": xc, "slam": sc_, "shuf": fc}

@@ -294,13 +294,8 @@ class PH:
         # _iter0_impl — the hook fires at the reference's point in the
         # sequence (ref:mpisppy/phbase.py:851 after _create_solvers)
         self._ext("iter0_post_solver_creation")
-        with _prof.annotate("wheel/iter0_solve"):
-            import time as _time
-            _t0 = _time.perf_counter()
+        with _prof.span(self.spcomm, "iter0_solve"):
             self.state, tb, cert = self._iter0_impl()
-            _dt = _time.perf_counter() - _t0
-        if self.spcomm is not None:
-            self.spcomm.emit_span("iter0_solve", _dt)
         self.trivial_bound = float(tb)
         self.trivial_bound_certified = bool(cert)
         self._ext("post_iter0")
@@ -317,25 +312,20 @@ class PH:
         t0 = time.time()
         for k in range(self._iter + 1, self.options.max_iterations + 1):
             self._iter = k
-            self._ext("miditer")
-            # the fused step solves + recomputes xbar/W in one program,
-            # so the solve-loop hooks bracket the whole jitted step
-            # (ref callout points: mpisppy/phbase.py:1016-1045)
-            self._ext("pre_solve_loop")
-            with _prof.annotate("wheel/subproblem_solve"):
-                t_solve = time.perf_counter()
-                self.state = self._iterk_impl()
-                dt_solve = time.perf_counter() - t_solve
-            if self.spcomm is not None:
-                # host wall of the step dispatch; with async XLA the
-                # device wait shows up in the next blocking read (the
-                # hub's harvest span) — docs/telemetry.md
-                self.spcomm.emit_span("subproblem_solve", dt_solve)
-            self._ext("post_solve_loop")
-            conv = self._read_conv()
-            self._ext("enditer")
-            if self.spcomm is not None:
-                self.spcomm.sync()
+            with _prof.annotate("wheel/iteration"):
+                self._ext("miditer")
+                # the fused step solves + recomputes xbar/W in one
+                # program, so the solve-loop hooks bracket the whole
+                # jitted step (ref callout points:
+                # mpisppy/phbase.py:1016-1045)
+                self._ext("pre_solve_loop")
+                with _prof.span(self.spcomm, "subproblem_solve"):
+                    self.state = self._iterk_impl()
+                self._ext("post_solve_loop")
+                conv = self._read_conv()
+                self._ext("enditer")
+                if self.spcomm is not None:
+                    self.spcomm.sync()
             self._ext("enditer_after_sync")
             global_toc(self._iter_msg(k, conv),
                        self.options.display_progress)
@@ -377,7 +367,10 @@ class PH:
         else:
             tb = self.trivial_bound
         conv = self.iterk_loop()
-        eobj = self.post_loops()
+        # the first segment of the wheel's `finalize` span; the second
+        # is WheelSpinner.spin's tail
+        with _prof.span(self.spcomm, "finalize"):
+            eobj = self.post_loops()
         return conv, eobj, tb
 
     # -- solution access (ref:spbase.py:561-672 analogs) -----------------

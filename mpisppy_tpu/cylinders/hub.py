@@ -123,6 +123,8 @@ class Hub(SPCommunicator):
         # the hub gets a private sink-less bus whose only subscriber is
         # the view.
         self.trace: list[dict] = []
+        # (name, hub_iter, t_end, dur_s) of every span event of the run
+        self.spans: list[tuple] = []
         self.telemetry = self.options.get("telemetry_bus") \
             or tel.EventBus()
         # a serve session passes its own id so the session's lifecycle
@@ -187,6 +189,7 @@ class Hub(SPCommunicator):
                 shrink_fn=self.options.get("watchdog_shrink_fn"),
             ).start()
         self._profiler = None
+        self._gc = None
         if self.options.get("profile_dir"):
             self._profiler = _prof.ProfilerSession(
                 self.options["profile_dir"],
@@ -202,10 +205,12 @@ class Hub(SPCommunicator):
         # evict it (see _note_contradiction).
         self._contra: dict[str, list] = {"outer": [], "inner": []}
 
-    def _emit(self, kind: str, _cyl: str = "hub", **data):
-        """Publish one event for this hub's run (no-op without sinks)."""
+    def _emit(self, kind: str, _cyl: str = "hub", _t: float | None = None,
+              **data):
+        """Publish one event for this hub's run (no-op without sinks);
+        `_t` back-dates its perf_counter stamp."""
         self.telemetry.emit(kind, run=self.run_id, cyl=_cyl,
-                            hub_iter=self._iter, **data)
+                            hub_iter=self._iter, t_mono=_t, **data)
 
     def _trace_token(self) -> dict:
         """The bus's current trace/span ids as set_session_context
@@ -216,23 +221,41 @@ class Hub(SPCommunicator):
             return {}
         return {"trace_id": ctx.trace_id, "span_id": ctx.span_id}
 
-    def emit_span(self, name: str, dur_s: float):
-        """One timed wheel phase (host wall seconds) onto the stream —
-        the analyzer's per-phase breakdown input.  Host-side semantics:
-        a span covers dispatch + any blocking reads inside it, so with
-        async XLA dispatch the device wait lands in whichever span
-        first reads a result (docs/telemetry.md)."""
-        self._emit(tel.SPAN, name=name, dur_s=dur_s)
+    def emit_span(self, name: str, dur_s: float,
+                  t_end: float | None = None):
+        """One timed wheel phase (host wall seconds, ending at the
+        perf_counter instant `t_end`, default now) onto the stream —
+        the analyzer's per-phase breakdown input.  Spans are disjoint
+        leaf phases (profiler.span); on the fused wheel the device wait
+        is the `scalar_wait` span, the loop's one blocking read
+        (docs/telemetry.md)."""
+        self._emit(tel.SPAN, _t=t_end, name=name, dur_s=dur_s)
 
-    @contextlib.contextmanager
     def _span(self, name: str):
         """Profiler annotation + SPAN event for one wheel phase."""
-        with _prof.annotate(f"wheel/{name}"):
-            t0 = time.perf_counter()
-            try:
-                yield
-            finally:
-                self.emit_span(name, time.perf_counter() - t0)
+        return _prof.span(self, name)
+
+    # -- collector pauses (docs/telemetry.md) -----------------------------
+    def watch_gc(self):
+        """Count the collector's pauses for this run (WheelSpinner.spin
+        starts it; the run's end stops it)."""
+        if self._gc is None:
+            self._gc = _prof.GCPauses().start()
+
+    def _flush_gc(self, stop: bool = False) -> dict:
+        """gc_n / gc_s since the last flush for the iteration row; each
+        pause of 1 ms or more becomes a `gc` span at its own time."""
+        pauses = self._gc
+        if pauses is None:
+            return {}
+        if stop:
+            pauses.stop()
+            self._gc = None
+        n, secs, long = pauses.take()
+        for t_end, dur, gen in long:
+            self._emit(tel.SPAN, _t=t_end, name="gc", dur_s=dur,
+                       generation=gen)
+        return {"gc_s": secs, "gc_n": n}
 
     def emit_run_end(self, reason: str, **extra):
         """Emit the run-end record (exit reason + final gap) exactly
@@ -244,6 +267,7 @@ class Hub(SPCommunicator):
         if getattr(self, "_run_ended", False):
             return
         self._run_ended = True
+        self._flush_gc(stop=True)
         abs_gap, rel_gap = self.compute_gaps()
         self._emit(tel.RUN_END, reason=reason,
                    outer=self.BestOuterBound, inner=self.BestInnerBound,
@@ -698,8 +722,9 @@ class PHHub(Hub):
             self._watchdog.beat(self._iter, self.BestOuterBound,
                                 self.BestInnerBound)
         extra = self._trace_extra()
+        gc_row = self._flush_gc()
         self._emit(tel.HUB_ITERATION, **{
-            "iter": self._iter, **extra,
+            "iter": self._iter, **extra, **gc_row,
             "outer": self.BestOuterBound, "inner": self.BestInnerBound,
             "abs_gap": abs_gap, "rel_gap": rel_gap,
             "ob_char": self.latest_ob_char, "ib_char": self.latest_ib_char,

@@ -20,6 +20,7 @@ import numpy as np
 
 from mpisppy_tpu import global_toc
 from mpisppy_tpu.resilience.faults import PreemptionError
+from mpisppy_tpu.telemetry import profiler as _prof
 
 
 class WheelSpinner:
@@ -39,22 +40,26 @@ class WheelSpinner:
         spin())."""
         if self.spcomm is not None:
             return self
-        hd = self.hub_dict
-        opt_class = hd["opt_class"]
-        self.opt = opt_class(**hd.get("opt_kwargs", {}))
+        # the `wheel_build` span reports through the hub it builds
+        with _prof.span(None, "wheel_build") as build_span:
+            hd = self.hub_dict
+            opt_class = hd["opt_class"]
+            self.opt = opt_class(**hd.get("opt_kwargs", {}))
 
-        spokes = []
-        for sd in self.list_of_spoke_dict:
-            spoke_class = sd["spoke_class"]
-            kw = dict(sd.get("opt_kwargs", {}))
-            spokes.append(spoke_class(self.opt, kw.get("options", kw)))
+            spokes = []
+            for sd in self.list_of_spoke_dict:
+                spoke_class = sd["spoke_class"]
+                kw = dict(sd.get("opt_kwargs", {}))
+                spokes.append(spoke_class(self.opt, kw.get("options", kw)))
 
-        hub_class = hd["hub_class"]
-        hub_kwargs = dict(hd.get("hub_kwargs", {}))
-        self.spcomm = hub_class(self.opt, hub_kwargs.get("options", {}),
-                                spokes=spokes)
-        self.spcomm.make_windows()
-        self.spcomm.setup_hub()
+            hub_class = hd["hub_class"]
+            hub_kwargs = dict(hd.get("hub_kwargs", {}))
+            self.spcomm = hub_class(self.opt,
+                                    hub_kwargs.get("options", {}),
+                                    spokes=spokes)
+            build_span.emitter = self.spcomm
+            self.spcomm.make_windows()
+            self.spcomm.setup_hub()
         return self
 
     def spin(self, comm_world=None):
@@ -74,6 +79,7 @@ class WheelSpinner:
         ckpt_path = self.spcomm.options.get("checkpoint_path")
         prev_handlers = self._install_preemption_handlers() \
             if ckpt_path else None
+        self.spcomm.watch_gc()
         try:
             self.spcomm.main()
         except PreemptionError as e:
@@ -102,10 +108,13 @@ class WheelSpinner:
                 _dispatch.clear_session_context()
             except Exception:
                 pass
-        self.spcomm.send_terminate()
-        self.spcomm.finalize()
-        self.spcomm.hub_finalize()
-        self.spcomm.free_windows()
+        # the second segment of the `finalize` span (the first times the
+        # PH algorithm's post_loops)
+        with _prof.span(self.spcomm, "finalize"):
+            self.spcomm.send_terminate()
+            self.spcomm.finalize()
+            self.spcomm.hub_finalize()
+            self.spcomm.free_windows()
         return self
 
     def _record_crash(self, reason: str, exc: BaseException) -> None:

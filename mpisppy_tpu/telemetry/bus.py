@@ -71,11 +71,13 @@ class EventBus:
     # -- emission ---------------------------------------------------------
     def emit(self, kind: str, *, run: str = "", cyl: str = "",
              hub_iter: int | None = None, level: int | None = None,
-             trace=None, **data) -> ev.Event | None:
+             trace=None, t_mono: float | None = None,
+             **data) -> ev.Event | None:
         """Publish one event to every subscriber.  Returns the Event (or
         None when nobody is listening — the no-telemetry fast path).
         `trace=` overrides the bus-scoped TraceContext for this one
-        event (the shared-bus attribution path)."""
+        event (the shared-bus attribution path); `t_mono=` stamps it
+        with the perf_counter instant it describes instead of now."""
         with self._lock:
             if not self._sinks or self.closed:
                 return None
@@ -84,7 +86,7 @@ class EventBus:
                                   hub_iter=hub_iter, level=level,
                                   trace=(trace if trace is not None
                                          else self._trace),
-                                  data=data)
+                                  data=data, t_mono=t_mono)
             dead = []
             last_err: dict[int, BaseException] = {}
             for sink in self._sinks:

@@ -197,11 +197,15 @@ class Event:
 
 def make_event(kind: str, seq: int, *, run: str = "", cyl: str = "",
                hub_iter: int | None = None, level: int | None = None,
-               trace=None, data: dict | None = None) -> Event:
+               trace=None, data: dict | None = None,
+               t_mono: float | None = None) -> Event:
     """`trace` is a TraceContext (or any object carrying
-    trace_id/span_id/parent_span_id) — None leaves the row unstamped."""
+    trace_id/span_id/parent_span_id) — None leaves the row unstamped.
+    `t_mono` back-dates the monotonic stamp to when the observed thing
+    ended (a span closed before it could be reported); default now."""
     return Event(kind=kind, seq=seq, t_wall=time.time(),
-                 t_mono=time.perf_counter(), run=run, cyl=cyl,
+                 t_mono=time.perf_counter() if t_mono is None else t_mono,
+                 run=run, cyl=cyl,
                  hub_iter=hub_iter, level=level,
                  trace_id=getattr(trace, "trace_id", "") or "",
                  span_id=getattr(trace, "span_id", "") or "",

@@ -97,10 +97,15 @@ def spokes():
     return [dict(s) for s in ALL_FUSED_SPOKES]
 
 
+#: the host-timing fields of a trajectory row: its clock stamp, the
+#: program spans filed under it and the collector's pauses
+HOST_TIMING = ("t", "spans", "gc_s", "gc_n")
+
+
 def trace_rows(ws):
-    """Hub trace rows with the wall-clock stamp stripped (the only
-    nondeterministic field in a trajectory row)."""
-    return [{k: v for k, v in row.items() if k != "t"}
+    """Hub trace rows with the host-timing fields stripped (the only
+    nondeterministic fields in a trajectory row)."""
+    return [{k: v for k, v in row.items() if k not in HOST_TIMING}
             for row in ws.spcomm.trace]
 
 

@@ -459,3 +459,196 @@ def test_cli_trace_jsonl_metrics_and_profile(tmp_path):
     for dirpath, _, filenames in os.walk(prof):
         prof_files += [os.path.join(dirpath, f) for f in filenames]
     assert prof_files, "profiler session wrote no trace"
+
+
+# ---------------------------------------------------------------------------
+# Program spans and collector pauses (profiler.span, GCPauses; the hub's
+# rows carry both — docs/telemetry.md)
+# ---------------------------------------------------------------------------
+class _Emitter:
+    def __init__(self):
+        self.calls = []
+
+    def emit_span(self, name, dur_s, t_end=None):
+        self.calls.append((name, dur_s, t_end))
+
+
+def test_span_emits_one_event_and_opens_its_annotation(monkeypatch):
+    from mpisppy_tpu.telemetry import profiler as prof
+    opened = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(prof, "annotate", Ann)
+    em = _Emitter()
+    import time
+    t0 = time.perf_counter()
+    with prof.span(em, "harvest"):
+        pass
+    assert opened == [("enter", "wheel/harvest"), ("exit", "wheel/harvest")]
+    (name, dur, t_end), = em.calls
+    assert name == "harvest" and 0.0 <= dur <= t_end - t0
+    # a nested span suspends the outer one: disjoint leaf segments
+    em.calls.clear()
+    with prof.span(em, "subproblem_solve"):
+        with prof.span(em, "scalar_wait"):
+            time.sleep(0.01)
+    names = [c[0] for c in em.calls]
+    assert names == ["subproblem_solve", "scalar_wait", "subproblem_solve"]
+    segs = [(t - d, t) for _, d, t in em.calls]
+    assert all(a[1] <= b[0] for a, b in zip(segs, segs[1:]))
+    assert em.calls[1][1] >= 0.01 > em.calls[0][1] + em.calls[2][1]
+    # an emitter learned mid-span (the wheel build) still gets the event
+    em.calls.clear()
+    with prof.span(None, "wheel_build") as sp:
+        sp.emitter = em
+    assert [c[0] for c in em.calls] == ["wheel_build"]
+
+
+def _sslp_fused_wheel(bus, max_iterations=12, extensions=None):
+    from mpisppy_tpu.algos import fused_wheel as fw
+    from mpisppy_tpu.cylinders.spoke import (
+        FusedLagrangianOuterBound, FusedXhatXbarInnerBound,
+    )
+    from mpisppy_tpu.models import sslp
+    inst = sslp.synthetic_instance(5, 15, seed=0)
+    specs = [sslp.scenario_creator(nm, instance=inst, num_scens=16,
+                                   lp_relax=True)
+             for nm in sslp.scenario_names_creator(16)]
+    batch = batch_mod.from_specs(specs)
+    opts = ph_mod.PHOptions(default_rho=20.0, max_iterations=max_iterations,
+                            conv_thresh=0.0, subproblem_windows=10,
+                            pdhg=pdhg.PDHGOptions(tol=1e-7))
+    hub = {"hub_class": PHHub,
+           "hub_kwargs": {"options": {"rel_gap": 1e-9,
+                                      "telemetry_bus": bus}},
+           "opt_class": fw.FusedPH,
+           "opt_kwargs": {"options": opts, "batch": batch,
+                          "wheel_options": fw.FusedWheelOptions(),
+                          "extensions": extensions}}
+    spokes = [{"spoke_class": FusedLagrangianOuterBound,
+               "opt_kwargs": {"options": {}}},
+              {"spoke_class": FusedXhatXbarInnerBound,
+               "opt_kwargs": {"options": {}}}]
+    return WheelSpinner(hub, spokes).spin()
+
+
+def test_fused_wheel_waits_once_per_iteration(tmp_path):
+    from mpisppy_tpu.telemetry import analyze as an
+    path = str(tmp_path / "trace.jsonl")
+    bus = telemetry.EventBus()
+    bus.subscribe(telemetry.JsonlSink(path))
+    ws = _sslp_fused_wheel(bus)
+    bus.close()
+    hub = ws.spcomm
+    # each row lists the spans until the next row: one scalar wait per
+    # hub iteration, plus iter0's in the first row (its own is the
+    # wait of iter0's sync) and finalize's flush in the last
+    waits = [sum(s[0] == "scalar_wait" for s in r["spans"])
+             for r in hub.trace]
+    assert waits == [2] + [1] * (len(hub.trace) - 1)
+    assert sum(len(r["spans"]) for r in hub.trace) == len(hub.spans)
+    names = {s[0] for s in hub.spans}
+    assert {"wheel_build", "iter0_solve", "subproblem_solve", "scalar_wait",
+            "harvest", "checkpoint", "finalize"} <= names
+    # leaf segments: the non-gc spans never overlap
+    segs = sorted((t - d, t) for n, _, t, d in hub.spans if n != "gc")
+    assert all(a[1] <= b[0] + 1e-9 for a, b in zip(segs, segs[1:]))
+    rep = an.analyze_path(path)
+    phases = rep["phases"]           # shares are rounded to 1e-6 each
+    assert sum(a["share"] for a in phases.values()) \
+        == pytest.approx(1.0, abs=1e-6 * len(phases))
+    assert phases["scalar_wait"]["calls"] == len(hub.trace) + 1
+    # every row carries the collector counters
+    assert all("gc_s" in r and "gc_n" in r for r in hub.trace)
+
+
+def test_forced_collection_reaches_the_next_row():
+    import gc
+
+    class CollectAt:
+        def __init__(self, opt):
+            self.opt = opt
+
+        def miditer(self):
+            if self.opt._iter == 3:
+                gc.collect()
+
+    before = list(gc.callbacks)
+    wheel = hub_dict(farmer_batch(3), max_iterations=5)
+    wheel["opt_kwargs"]["extensions"] = CollectAt
+    ws = WheelSpinner(wheel, [dict(d) for d in BOTH_SPOKES]).spin()
+    # loop iteration 3 ends in the row of hub iteration 4
+    row = {r["iter"]: r for r in ws.spcomm.trace}[4]
+    assert row["gc_n"] >= 1 and row["gc_s"] > 0.0
+    assert gc.callbacks == before        # finalize took the hook away
+
+
+def test_collection_under_the_bus_lock_cannot_deadlock():
+    import gc
+    import threading
+    from mpisppy_tpu.telemetry import profiler as prof
+    from mpisppy_tpu.telemetry.sinks import Sink
+
+    class CollectingSink(Sink):
+        def handle(self, event):       # runs with the bus lock held
+            gc.collect()
+
+    bus = telemetry.EventBus()
+    bus.subscribe(CollectingSink())
+    pauses = prof.GCPauses().start()
+    done = []
+
+    def run():
+        with bus._lock:
+            gc.collect()
+        bus.emit(telemetry.CONSOLE, msg="x")
+        done.append(pauses.take())
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(60)
+    pauses.stop()
+    assert not t.is_alive(), "collector hook deadlocked on the bus lock"
+    n, secs, _ = done[0]
+    assert n >= 2 and secs > 0.0
+
+
+def test_fused_step_hlo_identical_with_spans():
+    """The spans are host-side only: the fused step lowered from a
+    spun, span-emitting wheel is byte-identical to the step lowered
+    from iter0's state with no hub at all."""
+    from mpisppy_tpu.algos import fused_wheel as fw
+    bus = telemetry.EventBus()
+    bus.subscribe(telemetry.FlightRecorder(capacity=64))
+    ws = _sslp_fused_wheel(bus, max_iterations=3)
+    opt = ws.opt
+    kopts = ph_mod.kernel_opts(opt.options)
+    st0, _, _ = fw.fused_iter0(opt.batch, opt.rho, kopts, opt.wheel_options)
+    base = fw.fused_iterk.lower(opt.batch, st0, kopts,
+                                opt.wheel_options).as_text()
+    wired = fw.fused_iterk.lower(opt.batch, opt.wstate, kopts,
+                                 opt.wheel_options).as_text()
+    assert wired == base
+    assert any(s[0] == "scalar_wait" for s in ws.spcomm.spans)
+
+
+def test_span_cost_probe_runs():
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "tools"))
+    try:
+        import span_cost
+    finally:
+        sys.path.pop(0)
+    out = span_cost.span_cost(50)
+    assert out["us_per_iter"] > 0
+    # 5 segments per iteration (subproblem_solve twice), warm-up included
+    assert out["span_events"] >= 5 * 150
