@@ -277,9 +277,9 @@ def main(argv=None, root: str | None = None, require_tpu: bool = True,
             counts = window_counts(spec, rec)
             captured, run.captured = run.captured, []
             gc.collect()
-            from perfbench import check
+            from perfbench import check, system
             checks = check.run_checks(spec, rec, captured, run.check_at,
-                                      args.seed)
+                                      args.seed, system.probe)
             del captured
             summary = None
             if trace_dir is not None:

@@ -17,6 +17,9 @@ three bf16 passes).  The control of the output check is this reference
 one step lower, emulated explicitly so that it means the same on any
 backend: "emul-bf16x3" splits both operands into bf16 high and low parts
 and sums three bf16 products, "emul-bf16" rounds both to bf16 once.
+"split<n>-<p>" is <p> with the contraction summed in n consecutive parts,
+each at <p>, then added in order: as precise as <p>, rounded in another
+order.
 """
 from __future__ import annotations
 
@@ -108,6 +111,14 @@ def _split(v):
 
 def _dot(v, M, precision: str):
     """v @ M for (S, k) v and (k, j) M at the named precision."""
+    if precision.startswith("split"):
+        head, base = precision.split("-", 1)
+        n, k = int(head[len("split"):]), v.shape[-1]
+        cuts = [k * i // n for i in range(n + 1)]
+        out = _dot(v[..., :cuts[1]], M[:cuts[1]], base)
+        for a, b in zip(cuts[1:], cuts[2:]):
+            out = out + _dot(v[..., a:b], M[a:b], base)
+        return out
     if precision == "highest":
         return jnp.matmul(v, M, precision=HIGHEST)
     if precision == "bf16x3":
@@ -289,30 +300,35 @@ def _restart(P, s, o: Pdhg):
         done=done0 | newly, status=status)
 
 
-def _window(P, s, o: Pdhg):
+def _sweep(P, s, o: Pdhg):
+    """The iterations of one restart window, before its restart."""
     tau = o.step_margin * s["omega"] / s["Lnorm"]
     sigma = o.step_margin / (s["omega"] * s["Lnorm"])
     s = jax.lax.fori_loop(0, o.restart_period,
                           lambda _, v: _iterate(P, v, tau, sigma,
                                                 o.precision), s)
-    s = dict(s, nwin=s["nwin"] + o.restart_period)
-    return _restart(P, s, o)
+    return dict(s, nwin=s["nwin"] + o.restart_period)
+
+
+def _window(P, s, o: Pdhg):
+    return _restart(P, _sweep(P, s, o), o)
+
+
+def _fresh(s):
+    """A warm state as a fixed-budget solve starts from it: window sums,
+    anchors, counters and flags reset."""
+    return dict(s, x_sum=jnp.zeros_like(s["x"]), y_sum=jnp.zeros_like(s["y"]),
+                x_anchor=s["x"], y_anchor=s["y"],
+                nwin=jnp.zeros(s["omega"].shape, jnp.int32),
+                restart_score=jnp.full_like(s["omega"], jnp.inf),
+                done=jnp.zeros(s["omega"].shape, bool),
+                status=jnp.zeros(s["omega"].shape, jnp.int32))
 
 
 def solve_fixed(P, s, windows: int, o: Pdhg):
     """`windows` restart windows from a warm state, no early exit."""
-    s = dict(s, x_sum=jnp.zeros_like(s["x"]), y_sum=jnp.zeros_like(s["y"]),
-             x_anchor=s["x"], y_anchor=s["y"],
-             nwin=jnp.zeros(s["omega"].shape, jnp.int32),
-             restart_score=jnp.full_like(s["omega"], jnp.inf),
-             done=jnp.zeros(s["omega"].shape, bool),
-             status=jnp.zeros(s["omega"].shape, jnp.int32))
+    s = _fresh(s)
     return jax.lax.fori_loop(0, windows, lambda _, v: _window(P, v, o), s)
-
-
-def _judged(s):
-    """What the check reads of a plane's state; the rest is not kept."""
-    return {f: s[f] for f in ("x", "y")}
 
 
 # --------------------------------------------------------------------------
@@ -324,44 +340,58 @@ def _with_nonant_terms(P, lin, quad):
                 q=P["q"].at[:, idx].add(d * d * quad))
 
 
+def _prox(P, pre):
+    """The PH subproblems at the carried (W, x-bar)."""
+    rho = pre["rho"]
+    return _with_nonant_terms(P, pre["W"] - rho * pre["xbar"],
+                              jnp.broadcast_to(rho, pre["xbar"].shape))
+
+
 def ph_step(P, pre, windows: int, o: Pdhg):
     """PH: prox subproblems at (W, x-bar), then x-bar and W from the new
     iterates."""
     rho = pre["rho"]
-    Pe = _with_nonant_terms(P, pre["W"] - rho * pre["xbar"],
-                            jnp.broadcast_to(rho, pre["xbar"].shape))
-    s = solve_fixed(Pe, pre["hub"], windows, o)
+    s = solve_fixed(_prox(P, pre), pre["hub"], windows, o)
     x_non = P["d_non"] * s["x"][:, P["nonant_idx"]]
     p = P["p"][:, None]
     xbar = jnp.sum(p * x_non, 0) / jnp.maximum(jnp.sum(
         jnp.broadcast_to(p, x_non.shape), 0), 1e-30)
-    W = pre["W"] + rho * (x_non - xbar)
-    return {"hub": _judged(s), "xbar": xbar, "W": W}
+    return {"hub_x": s["x"], "xbar": xbar,
+            "W": pre["W"] + rho * (x_non - xbar)}
 
 
 def lag_step(P, W, solver, windows: int, o: Pdhg):
     """The Lagrangian bound at W: scenario LPs with W on the nonants."""
     Pl = _with_nonant_terms(P, W, jnp.zeros_like(W))
     s = solve_fixed(Pl, solver, windows, o)
-    _, rd, _ = kkt(Pl, s["x"], s["y"])
-    tol = jnp.maximum(o.tol, 5.0 * jnp.finfo(s["x"].dtype).eps)
-    certified = jnp.all(jnp.where(P["p"] > 0, rd <= 10.0 * tol, True))
-    bound = jnp.sum(P["p"] * dual_objective(Pl, s["x"], s["y"]))
-    return {"lag": _judged(s), "lag_bound": bound,
-            "lag_certified": certified}
+    return {"lag_bound": jnp.sum(P["p"] * dual_objective(Pl, s["x"],
+                                                         s["y"]))}
 
 
-def xhat_step(P, cand, solver, windows: int, o: Pdhg, tail: dict, gates):
-    """The recourse value of the candidate first stage: nonants fixed,
-    a warm fixed-budget solve, the straggler tail on the worst lanes,
-    then the feasibility- and compensation-gated expectation."""
+def _fixed(P, cand):
+    """The recourse LPs of the candidate first stage: nonants fixed."""
     idx, d = P["nonant_idx"], P["d_non"]
     S, ncol = P["c"].shape
     xs = cand / d
     lo = jnp.broadcast_to(P["l"], (S, ncol)).at[:, idx].set(xs)
     hi = jnp.broadcast_to(P["u"], (S, ncol)).at[:, idx].set(xs)
-    Pf = dict(P, l=lo, u=hi)
-    s = dict(solver, x=jnp.clip(solver["x"], lo, hi))
+    return dict(P, l=lo, u=hi)
+
+
+def _fixed_start(Pf, solver):
+    """The warm state clipped into the candidate's box, as the x-hat
+    plane starts."""
+    return dict(solver, x=jnp.clip(solver["x"], Pf["l"], Pf["u"]))
+
+
+def xhat_step(P, cand, solver, windows: int, o: Pdhg, tail: dict, gates):
+    """The recourse value of the candidate first stage: nonants fixed,
+    a warm fixed-budget solve, the straggler tail on the worst lanes,
+    then the feasibility- and compensation-gated expectation, the
+    expectation before the gates (`xhat_expect`), and the iterates they
+    were taken from."""
+    Pf = _fixed(P, cand)
+    s = _fixed_start(Pf, solver)
     s = solve_fixed(Pf, s, windows, dataclasses.replace(o,
                                                         detect_infeas=True))
     real = P["p"] > 0
@@ -380,20 +410,57 @@ def xhat_step(P, cand, solver, windows: int, o: Pdhg, tail: dict, gates):
 
         need = jnp.any(jnp.where(real, rp0 > gates["feas_tol"], False))
         s = jax.lax.cond(need, rescue, lambda v: v, s)
-    obj = jnp.sum(Pf["c"] * s["x"] + 0.5 * Pf["q"] * s["x"] ** 2, -1)
-    comp = gates["comp_safety"] * jnp.sum(
-        jnp.abs(s["y"]) * primal_residual(Pf, s["x"]), -1)
-    obj = obj + comp
-    rp, _, _ = kkt(Pf, s["x"], s["y"])
-    bad = (s["status"] == INFEASIBLE) | (s["status"] == UNBOUNDED)
+    obj, comp, rp, bad = _recourse(Pf, s, gates)
     feas = jnp.all(jnp.where(real, (rp <= gates["feas_tol"]) & ~bad, True))
     value = jnp.where(feas, jnp.sum(P["p"] * obj), jnp.inf)
     tight = jnp.sum(P["p"] * comp) <= gates["comp_tol"] * jnp.maximum(
         1.0, jnp.abs(value))
     feas = feas & tight
-    return {"xhat": _judged(s),
-            "xhat_value": jnp.where(feas, value, jnp.inf),
-            "xhat_feasible": feas}
+    return {"xhat_value": jnp.where(feas, value, jnp.inf),
+            "xhat_expect": jnp.sum(P["p"] * obj),
+            "xhat_x": s["x"], "xhat_y": s["y"], "xhat_status": s["status"]}
+
+
+def _recourse(Pf, s, gates):
+    """Each scenario's recourse objective with its compensation for the
+    residual infeasibility added, the compensation, the relative primal
+    residual, and whether a certificate of infeasibility or
+    unboundedness ended its solve."""
+    obj = jnp.sum(Pf["c"] * s["x"] + 0.5 * Pf["q"] * s["x"] ** 2, -1)
+    comp = gates["comp_safety"] * jnp.sum(
+        jnp.abs(s["y"]) * primal_residual(Pf, s["x"]), -1)
+    rp, _, _ = kkt(Pf, s["x"], s["y"])
+    bad = (s["status"] == INFEASIBLE) | (s["status"] == UNBOUNDED)
+    return obj + comp, comp, rp, bad
+
+
+@partial(jax.jit, static_argnames=("gates",))
+def gate_excess(P, cand, s, value, gates):
+    """The gates that a published inner bound `value` has to pass, on the
+    x-hat plane's iterates s ({"x", "y", "status"}) at `cand`: how far
+    they fall short, each as a share of its threshold (the worst real
+    scenario's primal residual over feas_tol, less 1; 1 for a scenario
+    certified infeasible or unbounded; the expected compensation over
+    comp_tol * max(1, |value|), less 1), 0 where all hold; and the
+    expectation of the compensated objective, ungated."""
+    gates = dict(gates)
+    Pf = _fixed(P, cand)
+    obj, comp, rp, bad = _recourse(Pf, s, gates)
+    real = P["p"] > 0
+    over = jnp.max(jnp.where(real, rp, 0.0)) / gates["feas_tol"] - 1.0
+    dead = jnp.any(real & bad).astype(obj.dtype)
+    loose = jnp.sum(P["p"] * comp) / (gates["comp_tol"] * jnp.maximum(
+        1.0, jnp.abs(value))) - 1.0
+    return {"excess": jnp.maximum(jnp.maximum(over, loose),
+                                  jnp.maximum(dead, 0.0)),
+            "expect": jnp.sum(P["p"] * obj)}
+
+
+def gates_of(wheel: dict) -> tuple:
+    """The x-hat plane's publication gates, from the configuration."""
+    return (("feas_tol", wheel["xhat_feas_tol"]),
+            ("comp_tol", wheel["xhat_comp_tol"]),
+            ("comp_safety", wheel["xhat_comp_safety"]))
 
 
 def plane_options(wheel: dict, precision: dict) -> dict:
@@ -443,14 +510,43 @@ def wheel_step(P, pre: dict, cand, windows: tuple, wheel: dict,
             ("windows", wheel["xhat_tail_windows"]),
             ("omega0", wheel["xhat_tail_omega0"]),
             ("restart_period", wheel["xhat_tail_restart_period"]))
-    gates = (("feas_tol", wheel["xhat_feas_tol"]),
-             ("comp_tol", wheel["xhat_comp_tol"]),
-             ("comp_safety", wheel["xhat_comp_safety"]))
     out = dict(_ph_jit(P, pre, windows=windows[0], o=opts["hub"]))
     out.update(_lag_jit(P, out["W"], pre["lag"], windows=windows[1],
                         o=opts["lag"]))
     out.update(_xhat_jit(P, cand, pre["xhat"], windows[2], opts["xhat"],
-                         tail, gates))
+                         tail, gates_of(wheel)))
     return out
 
 
+# --------------------------------------------------------------------------
+# the precision probe: one restart window of each plane
+# --------------------------------------------------------------------------
+def _probe_window(P, s, o: Pdhg) -> dict:
+    """One restart window from s, as solve_fixed runs its first: the
+    iterates after it (`x`, `y`), and the two that its restart chooses
+    between, the last iterate and the window's average.  The restart
+    keeps one of them in each scenario, on a comparison of their KKT
+    scores that a rounding can tip."""
+    s = _sweep(P, _fresh(s), o)
+    navg = jnp.maximum(s["nwin"], 1).astype(s["x"].dtype)[:, None]
+    r = _restart(P, s, o)
+    return {"x": r["x"], "y": r["y"], "x_last": s["x"], "y_last": s["y"],
+            "x_avg": s["x_sum"] / navg, "y_avg": s["y_sum"] / navg}
+
+
+@partial(jax.jit, static_argnames=("hub", "lag", "xhat"))
+def _probe_jit(P, pre, cand, hub, lag, xhat):
+    Pl = _with_nonant_terms(P, pre["W"], jnp.zeros_like(pre["W"]))
+    Pf = _fixed(P, cand)
+    return {"hub": _probe_window(_prox(P, pre), pre["hub"], hub),
+            "lag": _probe_window(Pl, pre["lag"], lag),
+            "xhat": _probe_window(Pf, _fixed_start(Pf, pre["xhat"]), xhat)}
+
+
+def probe_step(P, pre: dict, cand, wheel: dict, precision: dict) -> dict:
+    """One restart window of each plane, at its configured period, from
+    the carried state `pre`: the hub at (W, x-bar), the Lagrangian plane
+    at the W carried in, the x-hat plane at `cand` without its tail.
+    {plane: {"x", "y", "x_last", "y_last", "x_avg", "y_avg"}}"""
+    o = plane_options(wheel, precision)
+    return _probe_jit(P, pre, cand, o["hub"], o["lag"], o["xhat"])

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from mpisppy_tpu import generic_cylinders
 from mpisppy_tpu.algos import fused_wheel as fw
+from mpisppy_tpu.algos import ph as ph_mod
 from mpisppy_tpu.core import batch as batch_mod
 from mpisppy_tpu.core.tree import two_stage_tree
 from mpisppy_tpu.models import sslp as sslp_mod
@@ -175,15 +176,17 @@ class Hooks:
         k = self.opt._iter
         self.run.on_iteration_start(self, k)
         if (self.solve, k) in self.run.check_at:
-            self._pre = (step_inputs(self.opt.wstate), self._windows())
+            self._pre = (step_inputs(self.opt.wstate), self._windows(),
+                         program_inputs(self.opt))
 
     def post_solve_loop(self):
         k = self.opt._iter
         if self._pre is not None and (self.solve, k) in self.run.check_at:
-            pre, windows = self._pre
+            pre, windows, program = self._pre
             self.run.captured.append(
                 {"solve": self.solve, "iteration": k, "pre": pre,
-                 "post": step_outputs(self.opt.wstate), "windows": windows})
+                 "post": step_outputs(self.opt.wstate), "windows": windows,
+                 "program": program})
             self._pre = None
 
     def is_converged(self) -> bool:
@@ -243,8 +246,54 @@ class System:
         jax.block_until_ready(out)
 
 
-#: the PDHG fields a step is judged by
-JUDGED = ("x", "y")
+def probe(program: dict, cand, precision: dict | None = None) -> dict:
+    """One restart window of each plane, at its configured period, from
+    the state before a captured step (`program`, program_inputs),
+    through the programs the wheel dispatches with the options it runs
+    them with, but one window and the x-hat tail off: the Lagrangian
+    plane prices the W carried into the step, the x-hat plane evaluates
+    `cand`.  `precision` ({plane: iteration precision}, the control's)
+    replaces what the wheel runs.  {plane: {"x", "y"}}, as
+    reference.probe_step."""
+    batch, o, w = program["batch"], program["options"], program["wheel"]
+    precision = precision or {}
+
+    def at(p, plane):
+        return dataclasses.replace(p, iter_precision=precision.get(
+            plane, p.iter_precision))
+    hub = ph_mod.ph_iterk(batch, program["ph"], ph_mod.kernel_opts(
+        dataclasses.replace(o, subproblem_windows=1,
+                            pdhg=at(o.pdhg, "hub"))))
+    w1 = dataclasses.replace(w, lag_pdhg=at(w.lag_pdhg, "lag"),
+                             xhat_pdhg=at(w.xhat_pdhg, "xhat"),
+                             xhat_tail_windows=0)
+    lag = fw.lag_plane(batch, program["ph"].W, program["lag"], w1, 1)[0]
+    xhat = fw.xhat_plane(batch, cand, program["xhat"], w1, 1)[0]
+    return {"hub": _judged(hub.solver), "lag": _judged(lag),
+            "xhat": _judged(xhat)}
+
+
+def _judged(s) -> dict:
+    """References to the iterates the probe is judged by."""
+    return {"x": s.x, "y": s.y}
+
+
+def _warm(s):
+    """A solver state as a fixed-budget solve reads it: the window sums
+    and anchors, which solve_fixed resets, alias the iterates, so that
+    keeping the state keeps no buffer that step_inputs does not."""
+    return dataclasses.replace(s, x_sum=s.x, y_sum=s.y, x_anchor=s.x,
+                               y_anchor=s.y)
+
+
+def program_inputs(opt) -> dict:
+    """What `probe` needs of the wheel before a step: its batch and
+    options, and references to its PH and plane states."""
+    st = opt.wstate
+    return {"batch": opt.batch, "options": opt.options,
+            "wheel": opt.wheel_options,
+            "ph": dataclasses.replace(st.ph, solver=_warm(st.ph.solver)),
+            "lag": _warm(st.lag_solver), "xhat": _warm(st.xhat_solver)}
 
 
 def step_inputs(wstate) -> dict:
@@ -260,10 +309,8 @@ def step_inputs(wstate) -> dict:
 
 def step_outputs(wstate) -> dict:
     """References to the step's outputs that the check compares."""
-    def solver(s):
-        return {f: getattr(s, f) for f in JUDGED}
-    ph = wstate.ph
-    return {"hub": solver(ph.solver), "W": ph.W, "xbar": ph.xbar,
-            "lag": solver(wstate.lag_solver), "lag_bound": wstate.lag_bound,
-            "xhat": solver(wstate.xhat_solver),
-            "xhat_cand": wstate.xhat_cand, "xhat_value": wstate.xhat_value}
+    ph, xs = wstate.ph, wstate.xhat_solver
+    return {"hub_x": ph.solver.x, "W": ph.W, "xbar": ph.xbar,
+            "lag_bound": wstate.lag_bound, "xhat_cand": wstate.xhat_cand,
+            "xhat_value": wstate.xhat_value, "xhat_x": xs.x,
+            "xhat_y": xs.y, "xhat_status": xs.status}

@@ -1,6 +1,7 @@
-"""The control of the output check reads far above the program: on the
-CPU, at the tiny size, the program agrees with the reference to the last
-bit, and the reference one precision step lower does not."""
+"""The readings the output check's limits are set from, at the tiny size
+on the CPU: the program agrees with the reference to the last bit, the
+reference as precise but summed in another order reads correct, and the
+reference one precision step lower does not."""
 from __future__ import annotations
 
 import pytest
@@ -10,16 +11,26 @@ from perfbench.tests import tiny
 
 
 @pytest.fixture(scope="module")
-def spec(tmp_path_factory):
+def rows(tmp_path_factory):
     root = tiny.make_root(str(tmp_path_factory.mktemp("root")))
-    return harness.load_cell(root, tiny.CELL)
+    spec = harness.load_cell(root, tiny.CELL)
+    got = control.readings(spec, seed=2**32 + 3, seconds=3.0)
+    assert not [r for r in got if "missing_steps" in r]
+    steps = [r for r in got if "program" in r]
+    assert steps
+    return spec["limits"]["limits"], steps
 
 
-def test_control_fails_every_number_the_program_passes(spec):
-    rows = control.readings(spec, seed=2**32 + 3, seconds=3.0)
-    steps = [r for r in rows if "program" in r]
-    assert steps and not [r for r in rows if "missing_steps" in r]
-    limits = spec["limits"]["limits"]
+@pytest.mark.parametrize("reading", ["program", "reordered"])
+def test_sound_reading_reads_correct(reading, rows):
+    limits, steps = rows
+    for r in steps:
+        for name in check.NAMES:
+            assert r[reading][name] <= limits[name], (reading, r[reading])
+
+
+def test_control_fails_every_number_the_program_passes(rows):
+    limits, steps = rows
     for r in steps:
         for name in check.NAMES:
             assert r["program"][name] <= limits[name]

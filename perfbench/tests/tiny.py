@@ -19,9 +19,10 @@ def make_root(dest: str, limits: dict | None = None,
               unit: str = "solve") -> str:
     """A checkout-like root at `dest` holding BENCHMARK.json and perfbench/
     with the tiny cell added, its check held to `limits` (the content of
-    a perfbench/cells/<cell>.json) and its window counted in `unit`.  On the CPU the program and the
-    reference agree to rounding, so the default limits can sit far below
-    what any fault reads."""
+    a perfbench/cells/<cell>.json) and its window counted in `unit`.  On
+    the CPU the program and the reference agree to rounding, so the
+    default limits can sit far below what any fault reads (the precision
+    probe's is a multiple of a rounding's reading)."""
     shutil.copytree(BENCH, os.path.join(dest, "perfbench"),
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
@@ -41,7 +42,8 @@ def make_root(dest: str, limits: dict | None = None,
         "check": {"solves": 1, "iter_lo": 1, "iter_hi": 3},
         "trace": {"from_iteration": 2, "iterations": 2}})
     _dump(os.path.join(pb, "cells", CELL + ".json"), limits or {
-        "limits": dict.fromkeys(check.NAMES, 1e-5)})
+        "limits": dict(dict.fromkeys(check.NAMES, 1e-5),
+                       precision_probe=10.0)})
     with open(os.path.join(pb, "metrics", "tiny.solves.py"), "w") as f:
         f.write("def read(ctx):\n    return len(ctx['rec']['solves'])\n")
     bench["configs"].append({"name": "tiny", "source": "test",
